@@ -31,6 +31,8 @@ def main() -> None:
                     help="comma-separated bench module suffixes")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from . import (bench_partitioning, bench_sparsity, bench_dram,
                    bench_layout, bench_energy, bench_multicore,
                    bench_sim_throughput, bench_kernels, bench_roofline)

@@ -5,13 +5,12 @@ cycle-accurate systolic accelerator simulator plus the workload plane
 Public simulation API lives in `repro.api` (Simulator facade); the lower
 stage/engine layer in `repro.core`. See DESIGN.md for the map.
 """
-from . import compat  # noqa: F401  (installs jax API shims on old jax)
-
 # Trace toolchain at the top level: the legacy synthetic generators from
-# core.dram plus the dataflow-aware repro.trace subsystem.
-from .core.dram import (linear_trace, strided_trace,  # noqa: E402,F401
+# core.dram plus the dataflow-aware repro.trace subsystem.  Importing any
+# repro module initializes no JAX backend (a backend would take the chip).
+from .core.dram import (linear_trace, strided_trace,  # noqa: F401
                         tile_prefetch_trace)
-from .trace import (TraceSpec, gemm_request_stream,  # noqa: E402,F401
+from .trace import (TraceSpec, gemm_request_stream,  # noqa: F401
                     gemm_trace_stats, multicore_contention, trace_op,
                     trace_op_stats)
 

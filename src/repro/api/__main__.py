@@ -9,6 +9,8 @@ A thin delegate to `repro.api.study._main` — running the package module
 """
 import sys
 
+from ..compile_cache import enable_compile_cache
 from .study import _main
 
+enable_compile_cache()
 sys.exit(_main())

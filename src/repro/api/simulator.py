@@ -249,6 +249,12 @@ class Simulator:
 # flavors a process actually sweeps.
 _SWEEP_FN_CACHE: Dict[tuple, object] = {}
 
+# Requests one device replays per block of a trace sweep (512 streams at
+# the default 4096-request cap).  The XLA replay driver holds ~0.8 KB of
+# intermediates per request, so a block stays near 1.6 GB of device
+# memory however many (design, op) streams the sweep replays.
+_REPLAY_BLOCK_REQUESTS = 1 << 21
+
 
 def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                        dram: Optional[DramConfig] = None, spec=None,
@@ -257,7 +263,8 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                        layout=None, r_cap: int = 0,
                        representation: str = "ellpack_block",
                        with_sparsity: bool = False,
-                       noc: Optional[str] = None):
+                       noc: Optional[str] = None,
+                       device_mesh: Optional[jax.sharding.Mesh] = None):
     """Jitted (vmap over designs) sweep kernel, cached module-wide (see
     `_SWEEP_FN_CACHE`) so repeated sweeps — benchmark loops, serving
     traffic, new Simulator sessions — reuse the compiled executable.
@@ -270,7 +277,8 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
     fields shaping the conflict model; None skips the layout math
     entirely — the plan groups enabled and disabled cells separately),
     `r_cap` (static bound on array rows for the layout window) and the
-    sparse metadata `representation`.
+    sparse metadata `representation`.  `device_mesh` (a device mesh of
+    more than one device, or None) splits the trace replay over devices.
 
     With `dram` set (trace fidelity), the first-order stall is replaced by
     the cycle-accurate stall of each op's generated demand trace.  The
@@ -292,7 +300,7 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
     # key matches what result metadata reports
     key = (dataflow, word_bytes, ert, dram, spec,
            _rp.resolve_engine_runtime(engine), mesh_shape,
-           layout, r_cap, representation, with_sparsity, noc)
+           layout, r_cap, representation, with_sparsity, noc, device_mesh)
     cached = _SWEEP_FN_CACHE.get(key)
     if cached is not None:
         return cached
@@ -302,6 +310,7 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
         spec = spec or DEFAULT_SPEC
     Pr, Pc = mesh_shape
     num_cores = Pr * Pc
+    n_dev = 1 if device_mesh is None else device_mesh.size
 
     def _mem(d):
         return MemoryConfig(ifmap_sram_bytes=d["if_b"],
@@ -344,28 +353,55 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
 
     def _trace_stalls(sdesign, smap, M, N, K, ov, on, om):
         """(designs, ops) cycle-accurate stalls: one replay per unique
-        stream design, decode hoisted out of the per-design closure."""
+        (stream design, op) pair, decode hoisted out of the per-pair
+        closure.  The pairs replay in blocks of at most
+        `_REPLAY_BLOCK_REQUESTS` requests per device (one `lax.map` step
+        each), which bounds device memory whatever the grid and workload
+        size; on a device mesh each block's pairs split over the devices."""
+        n_ops = M.shape[0]
+        n_pairs = next(iter(sdesign.values())).shape[0] * n_ops
+        per_dev = max(1, _REPLAY_BLOCK_REQUESTS // spec.cap)
+        blk = min(per_dev, -(-n_pairs // n_dev)) * n_dev
+        n_blk = -(-n_pairs // blk)
 
         def _replay(t, fb, ch, row, wbit, val):
             return replay_requests(t, fb, ch, row, wbit, val, dram,
                                    spec.gran_bytes, engine=engine,
                                    ).stall_cycles
 
-        t, addr, wbit, val, scale = jax.vmap(
-            _op_streams, in_axes=(0,) + (None,) * 6)(
-                sdesign, M, N, K, ov, on, om)
-        fb, ch, row = decode_requests(addr, dram)   # one flat decode
-        if engine in ("xla", "pallas"):
-            # batch-native: the whole (streams, ops) batch goes through
-            # one chunk scan ("xla") or one megakernel launch with the
-            # batch flattened onto the Pallas grid ("pallas") — never a
-            # vmapped per-stream replay, and "pallas" never silently
-            # rides the "xla" driver (replay_decoded resolves it to the
-            # megakernel on TPU or its interpret/twin form off-TPU)
-            stall = _replay(t, fb, ch, row, wbit, val)
-        else:
-            stall = jax.vmap(jax.vmap(_replay))(t, fb, ch, row, wbit, val)
-        return (stall * scale)[smap]
+        def replay_block(pairs, sdesign, M, N, K, ov, on, om):
+            def one(p):
+                d = {k: v[p // n_ops] for k, v in sdesign.items()}
+                o = [x[p % n_ops][None] for x in (M, N, K, ov, on, om)]
+                return tuple(x[0] for x in _op_streams(d, *o))
+
+            t, addr, wbit, val, scale = jax.vmap(one)(pairs)
+            fb, ch, row = decode_requests(addr, dram)   # one flat decode
+            if engine in ("xla", "pallas"):
+                # batch-native: the block goes through one chunk scan
+                # ("xla") or one megakernel launch with the streams on
+                # the Pallas grid ("pallas") — never a vmapped per-stream
+                # replay, and "pallas" never silently rides the "xla"
+                # driver (replay_decoded resolves it to the megakernel on
+                # TPU or its interpret/twin form off-TPU)
+                stall = _replay(t, fb, ch, row, wbit, val)
+            else:
+                stall = jax.vmap(_replay)(t, fb, ch, row, wbit, val)
+            return stall * scale
+
+        if n_dev > 1:
+            from jax.sharding import PartitionSpec as P
+            axes = P(tuple(device_mesh.axis_names))
+            replay_block = jax.shard_map(
+                replay_block, mesh=device_mesh,
+                in_specs=(axes,) + (P(),) * 7, out_specs=axes,
+                check_vma=False)
+        # padding pairs repeat the last one; their stalls are dropped
+        pairs = jnp.minimum(jnp.arange(n_blk * blk, dtype=jnp.int32),
+                            n_pairs - 1).reshape(n_blk, blk)
+        stall = jax.lax.map(
+            lambda p: replay_block(p, sdesign, M, N, K, ov, on, om), pairs)
+        return stall.reshape(-1)[:n_pairs].reshape(-1, n_ops)[smap]
 
     def one_design(d, M, N, K, cnt, ov, on, om, velems, vcnt, trace_stall):
         mem = _mem(d)
@@ -625,7 +661,9 @@ def _sweep_batched(cfgs: Sequence[AcceleratorConfig], ops: Sequence[Op],
                             engine=engine, mesh_shape=(Pr, Pc),
                             layout=layout_key, r_cap=r_cap,
                             representation=representation,
-                            with_sparsity=with_sparsity, noc=noc_kind)
+                            with_sparsity=with_sparsity, noc=noc_kind,
+                            device_mesh=(mesh if mesh is not None
+                                         and mesh.size > 1 else None))
     res = fn(design, sdesign, smap_arr, M, N, K, cnt, ov, on, om,
              velems, vcnt)
     return {k: np.asarray(v, np.float64)[:n] for k, v in res.items()}

@@ -88,6 +88,14 @@ def _flag_non_finite(metrics: Dict[str, float]) -> None:
             return
 
 
+def _record(errors: Optional[List[Dict[str, object]]], group: str,
+            cells: Sequence[int], exc: BaseException) -> None:
+    """Keep what a failed group or cell raised (type and message)."""
+    if errors is not None:
+        errors.append({"group": group, "cells": [int(i) for i in cells],
+                       "error": f"{type(exc).__name__}: {exc}"})
+
+
 def _code_digest(code) -> str:
     """Process-stable digest of a code object: bytecode + literal
     constants (recursing into nested code objects, whose default reprs
@@ -877,15 +885,20 @@ class Study:
         """
         cache_dir = cache if cache is not None else self._cache_dir
         plan = self.plan()
+        errors: List[Dict[str, object]] = []
         results, executed, hits = self._execute_cells(
-            plan, cache_dir=cache_dir, mesh=mesh)
-        return self._frame(plan.cells,
-                           [results[i] for i in range(len(plan.cells))],
-                           executed, hits)
+            plan, cache_dir=cache_dir, mesh=mesh, errors=errors)
+        res = self._frame(plan.cells,
+                          [results[i] for i in range(len(plan.cells))],
+                          executed, hits)
+        if errors:
+            res.meta["cell_errors"] = errors
+        return res
 
     def _execute_cells(self, plan: StudyPlan,
                        indices: Optional[Sequence[int]] = None, *,
-                       cache_dir: Optional[str] = None, mesh=None
+                       cache_dir: Optional[str] = None, mesh=None,
+                       errors: Optional[List[Dict[str, object]]] = None
                        ) -> Tuple[Dict[int, Dict[str, float]], int, int]:
         """Execute a subset of the plan's cells (default: all of them).
 
@@ -903,7 +916,10 @@ class Study:
         in the frame — instead of poisoning the whole study/shard.
         `ValueError` is the deliberate exception: it marks an invalid
         configuration (validation is loud and early), so it propagates
-        rather than silently degrading.
+        rather than silently degrading.  What a failed group or cell
+        raised is appended to `errors`, when given, as
+        `{"group", "cells", "error"}` (`run()` puts the list in
+        `meta["cell_errors"]`): a device fault must not leave only NaN.
         Completed cells checkpoint to the cache as they finish, so a
         killed long run resumes from its last completed cell on re-run.
         """
@@ -960,9 +976,11 @@ class Study:
                                    vals["total_cycles"])
             except ValueError:
                 raise    # invalid configuration: loud, never a failed cell
-            except Exception:  # noqa: BLE001 — group fails, study lives
+            except Exception as e:  # noqa: BLE001 — group fails, study lives
                 for i in miss:
                     results[i] = {"batched": 1.0, "cell_status": 1.0}
+                _record(errors, f"{grp.workload}/{grp.fidelity}/"
+                        f"{grp.dataflow}", miss, e)
                 continue
             for j, i in enumerate(miss):
                 results[i] = {k: float(v[j]) for k, v in vals.items()}
@@ -1013,8 +1031,10 @@ class Study:
                             zip(rep.ops, (op.count for op in ops)))
             except ValueError:
                 raise    # invalid configuration: loud, never a failed cell
-            except Exception:  # noqa: BLE001 — one bad cell, study lives
+            except Exception as e:  # noqa: BLE001 — one bad cell, study lives
                 results[i] = {"batched": 0.0, "cell_status": 1.0}
+                _record(errors, f"{cell.workload}/{cell.fidelity}/"
+                        f"{cell.design}", [i], e)
                 continue
             m["batched"] = 0.0
             results[i] = m
@@ -1438,6 +1458,9 @@ def _main(argv: Optional[Sequence[str]] = None) -> int:
         for k, v in sorted(res.meta.items()):
             if k != "search_log":
                 print(f"  {k} = {v}")
+    for err in res.meta.get("cell_errors", []):
+        print(f"cell error: {err['group']} ({len(err['cells'])} cells): "
+              f"{err['error']}")
     claims = res.check_claims()
     for name, ok in claims.items():
         print(f"claim {'PASS' if ok else 'FAIL'}: {name}")

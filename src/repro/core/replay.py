@@ -576,14 +576,7 @@ def replay_decoded(t_issue, flat_bank, ch, row, is_write, valid,
     """
     n = t_issue.shape[-1]
     batch = t_issue.shape[:-1]
-    C = DEFAULT_CHUNK if chunk is None else int(chunk)
-    C = max(1, min(C, max(n, 1)))
-    ch_n, bk_n = cfg.channels, cfg.banks_per_channel
-    Qr, Qw = cfg.read_queue, cfg.write_queue
     passes = None if max_passes is None else max(1, int(max_passes))
-    n_qg = ch_n if per_channel_queues else 1
-    busy = float(max(1.0, gran_bytes / cfg.bandwidth_bytes_per_cycle))
-    f32 = jnp.float32
 
     if core_id is None:
         core_id = jnp.zeros(t_issue.shape, jnp.int32)
@@ -591,16 +584,26 @@ def replay_decoded(t_issue, flat_bank, ch, row, is_write, valid,
     if engine == "pallas":
         resolved = resolve_engine_runtime("pallas", interpret)
         if resolved != "pallas:twin":
+            # on TPU this is always the compiled kernel: it runs or
+            # raises (the megakernel picks its own lane-aligned chunk)
             from ..kernels.replay.megakernel import replay_megakernel
             return replay_megakernel(
                 t_issue, flat_bank, ch, row, is_write, valid, cfg,
-                gran_bytes, chunk=C, max_passes=passes, tol=float(tol),
+                gran_bytes, chunk=chunk, max_passes=passes, tol=float(tol),
                 n_cores=n_cores, core_id=core_id,
                 per_channel_queues=per_channel_queues,
                 interpret=(resolved == "pallas:interpret"))
-        # fall through: the twin is this driver (same model, same
-        # fixed-point contract; the megakernel's chunk math is
+        # fall through, off-TPU only: the twin is this driver (same
+        # model, same fixed-point contract; the megakernel is
         # differentially pinned to it and to the reference oracle)
+
+    C = DEFAULT_CHUNK if chunk is None else int(chunk)
+    C = max(1, min(C, max(n, 1)))
+    ch_n, bk_n = cfg.channels, cfg.banks_per_channel
+    Qr, Qw = cfg.read_queue, cfg.write_queue
+    n_qg = ch_n if per_channel_queues else 1
+    busy = float(max(1.0, gran_bytes / cfg.bandwidth_bytes_per_cycle))
+    f32 = jnp.float32
 
     pad = (-n) % C
     nc = (n + pad) // C

@@ -63,6 +63,10 @@ def _cmd_serve(args) -> int:
 
 
 def _cmd_worker(args) -> int:
+    # the one farm process that compiles; the broker, client and smoke
+    # parent stay off JAX (each worker process holds one chip)
+    from ..compile_cache import enable_compile_cache
+    enable_compile_cache()
     worker = Worker(args.root, args.id, use_mesh=args.mesh,
                     cache=None if args.no_cache else "auto")
     print(f"farm worker {worker.worker_id} serving "

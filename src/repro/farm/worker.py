@@ -67,9 +67,11 @@ class Worker:
         """Shape a data mesh over this process's devices via the elastic
         planner (batched groups shard their design axis over it)."""
         import jax
+
+        from ..launch.mesh import auto_mesh
         n = len(jax.devices())
         self._mesh_plan = plan_elastic_remesh(n, global_batch=n)
-        self._mesh = jax.make_mesh((self._mesh_plan.dp,), ("data",))
+        self._mesh = auto_mesh((self._mesh_plan.dp,), ("data",))
 
     # ---- the work loop -------------------------------------------------------
     def step(self) -> bool:

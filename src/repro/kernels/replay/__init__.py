@@ -1,9 +1,9 @@
-"""Fused trace-replay kernels: shared chunk math + the Pallas megakernel.
+"""Fused trace-replay kernels: the per-chunk math + the Pallas megakernel.
 
-`chunkmath` is the single implementation of the chunked bank-parallel
-replay step; `megakernel` wraps it in one `pallas_call` over a grid of
-streams, and `core.replay.replay_decoded` traces the same functions
-through XLA as the CPU twin.
+`chunkmath` is the megakernel's per-chunk replay step in the 2-D layout
+Mosaic lowers, plus the fixed-point schedule (`iterate_fixed_point`)
+that `core.replay.replay_decoded`'s XLA driver shares; `megakernel`
+wraps the step in one `pallas_call` over a grid of streams.
 """
 from .chunkmath import (ChunkState, ChunkTables, chunk_resolve,
                         chunk_tables, init_state, iterate_fixed_point)

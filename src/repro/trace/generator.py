@@ -51,7 +51,9 @@ from ..core.workloads import Op
 # per-core offsets of the contention path (which guards the <= 16-core
 # limit of the 2^31 shared address space explicitly).
 REGION_SPAN = 1 << 25
-_BIG_T = jnp.float32(1e15)          # sort key for invalid (masked) slots
+# Sort key for invalid (masked) slots. A Python float: a jnp scalar here
+# would initialize a JAX backend (and take the chip) on import.
+_BIG_T = 1e15
 # Compressed streams are sampled in contiguous runs of this many granules
 # (64 granules x 64 B = two 2 KiB DRAM rows) so layout-driven row-buffer
 # locality survives stream compression.

@@ -11,6 +11,7 @@ from repro.core import (AcceleratorConfig, simulate_network, simulate_op,
                         tpu_like_config)
 from repro.core.accelerator import LayoutConfig, SparsityConfig
 from repro.core.workloads import Op, resnet18
+from repro.launch.mesh import auto_mesh
 
 
 # ---- facade parity ---------------------------------------------------------
@@ -194,7 +195,7 @@ def test_sweep_mixed_grid_batches_sparse_cells():
 
 def test_sweep_sharded_over_host_mesh():
     import jax
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = auto_mesh((len(jax.devices()),), ("data",))
     grid = preset_grid(array=[8, 16, 32], sram_mb=[1.0])   # pads to size
     res = Simulator().sweep(grid, OPS[:1], mesh=mesh)
     rep = simulate_network(grid[1], OPS[:1])

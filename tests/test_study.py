@@ -10,6 +10,7 @@ import pytest
 from repro.api import (Simulator, Study, StudyResult, get_study,
                        list_studies, preset_grid, register_study, studies)
 from repro.core.workloads import Op
+from repro.launch.mesh import auto_mesh
 
 OPS_A = [Op("a", 256, 1024, 512), Op("b", 512, 197, 768, count=3.0),
          Op("v", kind="vector", vector_elems=8192.0, count=2.0)]
@@ -89,7 +90,7 @@ def test_sparse_cells_batch_and_oracle_stays_reachable():
 
 def test_sharded_vs_unsharded_equality():
     import jax
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = auto_mesh((len(jax.devices()),), ("data",))
     grid = preset_grid(array=[8, 16, 32], sram_mb=[1.0])
     mk = lambda: (Study().designs(grid).workloads({"wa": OPS_A[:1]})
                   .fidelity("fast"))
@@ -97,6 +98,24 @@ def test_sharded_vs_unsharded_equality():
     shard = mk().run(mesh=mesh)
     for k in ("total_cycles", "energy_pj", "stall_cycles", "utilization"):
         assert np.allclose(plain[k], shard[k], rtol=1e-6)
+
+
+def test_trace_replay_blocks_do_not_change_results(monkeypatch):
+    """The trace sweep replays its (design, op) streams in blocks that
+    bound device memory; streams replay independently, so the block size
+    (here 3 streams per `lax.map` step, with a padded last block) keeps
+    every result within the replay's 1e-3 contract (XLA vectorizes a
+    different batch, and the fixed point stops within `tol` cycles)."""
+    from repro.api import simulator as sim
+    grid = preset_grid(array=[16, 32], sram_mb=[0.5, 2.0])
+    mk = lambda: (Study().designs(grid).workloads({"wa": OPS_A})
+                  .fidelity("trace"))
+    whole = mk().run()
+    monkeypatch.setattr(sim, "_SWEEP_FN_CACHE", {})
+    monkeypatch.setattr(sim, "_REPLAY_BLOCK_REQUESTS", 3 * 4096)
+    blocked = mk().run()
+    for k in ("total_cycles", "stall_cycles", "energy_pj"):
+        np.testing.assert_allclose(blocked[k], whole[k], rtol=1e-3)
 
 
 # ---- frame ops on a known 3-design fixture --------------------------------
@@ -481,6 +500,31 @@ def test_evaluator_exception_degrades_to_failed_cell():
     assert len(ok) == 2 and (ok["cell_status"] == 0.0).all()
     assert res.argbest("m") == 0          # NaN row never wins
     assert res.best("m")["design"] == res["design"][0]
+
+
+def test_batched_group_exception_recorded_in_meta(monkeypatch):
+    """A batched group whose sweep raises (a device fault, a compiler
+    refusal) degrades to failed cells AND keeps what it raised: the
+    exception type and message land in meta["cell_errors"]."""
+    import repro.api.study as study_mod
+
+    def boom(*a, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    monkeypatch.setattr(study_mod, "_sweep_batched", boom)
+    res = (Study("faulty").designs(preset_grid(array=[8, 16]))
+           .workloads({"w": OPS_B[:1]}).fidelity("fast").run())
+    assert res.failed_cells == [0, 1]
+    errs = res.meta["cell_errors"]
+    assert len(errs) == 1 and errs[0]["cells"] == [0, 1]
+    assert errs[0]["error"] == ("RuntimeError: RESOURCE_EXHAUSTED: "
+                                "out of device memory")
+    assert errs[0]["group"].startswith("w/fast/")
+    # a healthy study carries no error record
+    monkeypatch.undo()
+    ok = (Study("fine").designs(preset_grid(array=[8]))
+          .workloads({"w": OPS_B[:1]}).fidelity("fast").run())
+    assert "cell_errors" not in ok.meta
 
 
 def test_non_finite_canonical_metrics_flag_cell_failed():
