@@ -1,0 +1,79 @@
+"""Faults planted under the timed path, for the tests of `correct`.
+
+Each is a context manager that breaks one thing the program produces
+while a run of the harness drives it; the Study's compiled sweep
+programs are dropped on entry and exit so that the broken code is the
+code that runs."""
+import contextlib
+import dataclasses
+
+import numpy as np
+
+
+@contextlib.contextmanager
+def _patched(module, name, make):
+    from repro.api import simulator
+    real = getattr(module, name)
+    saved = dict(simulator._SWEEP_FN_CACHE)
+    simulator._SWEEP_FN_CACHE.clear()
+    setattr(module, name, make(real))
+    try:
+        yield
+    finally:
+        setattr(module, name, real)
+        simulator._SWEEP_FN_CACHE.clear()
+        simulator._SWEEP_FN_CACHE.update(saved)
+
+
+def state_unchanged(fidelity):
+    """The DRAM model never advances: the replay (trace) or the stall
+    stage (fast) hands back no stall."""
+    if fidelity == "trace":
+        from repro.core import dram
+
+        def make(real):
+            def broken(*a, **k):
+                r = real(*a, **k)
+                return dataclasses.replace(
+                    r, stall_cycles=r.stall_cycles * 0.0)
+            return broken
+        return _patched(dram, "replay_requests", make)
+    from repro.core import stages
+
+    def make(real):
+        def broken(*a, **k):
+            s = real(*a, **k)
+            return dict(s, stall_cycles=s["stall_cycles"] * 0.0)
+        return broken
+    return _patched(stages, "traced_op_stats", make)
+
+
+def half_batch(fidelity=None):
+    """Half of each group's designs left out; they read the mean of the
+    rest."""
+    from repro.api import study
+
+    def make(real):
+        def broken(cfgs, *a, **k):
+            h = max(1, len(cfgs) // 2)
+            out = real(list(cfgs[:h]), *a, **k)
+            return {c: np.concatenate([v, np.full(len(cfgs) - h, v.mean())])
+                    for c, v in out.items()}
+        return broken
+    return _patched(study, "_sweep_batched", make)
+
+
+def answer_altered(fidelity=None):
+    """Every cell's energy 1 % off where the sweep kernel produces it."""
+    from repro.api import simulator
+
+    def make(real):
+        def broken(counts, ert):
+            e = real(counts, ert)
+            return dict(e, total=e["total"] * 1.01)
+        return broken
+    return _patched(simulator, "energy_pj", make)
+
+
+FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,
+          "answer_altered": answer_altered}
