@@ -9,7 +9,6 @@ Prints ``name,us_per_call,derived`` CSV. Modules:
   bench_multicore      Table VI iso-compute + heterogeneous cores
   bench_sim_throughput Table IV analog + batched Simulator.sweep path
   bench_kernels        Pallas kernel microbenchmarks
-  bench_roofline       dry-run roofline table (EXPERIMENTS.md source)
 
 ``--smoke`` runs every module on reduced grids (CI / quick sanity);
 ``--only mod1,mod2`` restricts the module list.
@@ -35,10 +34,10 @@ def main() -> None:
     enable_compile_cache()
     from . import (bench_partitioning, bench_sparsity, bench_dram,
                    bench_layout, bench_energy, bench_multicore,
-                   bench_sim_throughput, bench_kernels, bench_roofline)
+                   bench_sim_throughput, bench_kernels)
     mods = [bench_partitioning, bench_sparsity, bench_dram, bench_layout,
             bench_energy, bench_multicore, bench_sim_throughput,
-            bench_kernels, bench_roofline]
+            bench_kernels]
     if args.only:
         want = {w.strip() for w in args.only.split(",") if w.strip()}
         known = {m.__name__.split("bench_")[-1] for m in mods}

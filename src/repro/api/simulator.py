@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import spans
 from ..core import dataflow as dfm
 from ..core import stages as st
 from ..core.accelerator import (AcceleratorConfig, DramConfig, MemoryConfig,
@@ -256,6 +257,37 @@ _SWEEP_FN_CACHE: Dict[tuple, object] = {}
 _REPLAY_BLOCK_REQUESTS = 1 << 21
 
 
+def _replay_blocks(n_pairs: int, cap: int, n_dev: int = 1) -> tuple:
+    """(streams a replay block holds, blocks) for `n_pairs` streams of
+    `cap` requests split over `n_dev` devices."""
+    per_dev = max(1, _REPLAY_BLOCK_REQUESTS // cap)
+    blk = min(per_dev, -(-n_pairs // n_dev)) * n_dev
+    return blk, -(-n_pairs // blk)
+
+
+def _program_name(dataflow: str, dram: Optional[DramConfig], engine: str,
+                  mesh_shape: tuple, layout, with_sparsity: bool,
+                  noc: Optional[str]) -> str:
+    """The sweep program's name, as the device trace shows it (`jit_`
+    prefixed): fidelity, dataflow, then what else sets the flavor apart,
+    e.g. `sweep_trace_os_ch2_bw19p2_lay64`."""
+    parts = ["sweep", "fast" if dram is None else "trace", dataflow]
+    if dram is not None:
+        bw = f"{dram.bandwidth_bytes_per_cycle:g}".replace(".", "p")
+        parts += [f"ch{dram.channels}", f"bw{bw}"]
+        if engine != "xla":
+            parts.append(engine.replace(":", "_"))
+    if mesh_shape != (1, 1):
+        parts.append("x".join(map(str, mesh_shape)))
+    if layout is not None:
+        parts.append(f"lay{layout.num_banks}")
+    if with_sparsity:
+        parts.append("sparse")
+    if noc is not None:
+        parts.append(noc)
+    return "_".join(parts)
+
+
 def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                        dram: Optional[DramConfig] = None, spec=None,
                        engine: Optional[str] = None,
@@ -298,8 +330,8 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
     # "pallas:interpret" off-TPU), not the requested name: a "pallas"
     # sweep must never alias an "xla" cache entry, and the label in the
     # key matches what result metadata reports
-    key = (dataflow, word_bytes, ert, dram, spec,
-           _rp.resolve_engine_runtime(engine), mesh_shape,
+    runtime = _rp.resolve_engine_runtime(engine)
+    key = (dataflow, word_bytes, ert, dram, spec, runtime, mesh_shape,
            layout, r_cap, representation, with_sparsity, noc, device_mesh)
     cached = _SWEEP_FN_CACHE.get(key)
     if cached is not None:
@@ -334,6 +366,7 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                       nop=d["nop"], Pr=Pr, Pc=Pc)
         return sp, mc
 
+    @jax.named_scope(spans.GENERATE)
     def _op_streams(d, M, N, K, ov, on, om):
         """Generated demand streams for every gemm op of one design,
         driven by the *effective* compute window and the sparsity-shrunk
@@ -360,10 +393,9 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
         size; on a device mesh each block's pairs split over the devices."""
         n_ops = M.shape[0]
         n_pairs = next(iter(sdesign.values())).shape[0] * n_ops
-        per_dev = max(1, _REPLAY_BLOCK_REQUESTS // spec.cap)
-        blk = min(per_dev, -(-n_pairs // n_dev)) * n_dev
-        n_blk = -(-n_pairs // blk)
+        blk, n_blk = _replay_blocks(n_pairs, spec.cap, n_dev)
 
+        @jax.named_scope(spans.REPLAY)
         def _replay(t, fb, ch, row, wbit, val):
             return replay_requests(t, fb, ch, row, wbit, val, dram,
                                    spec.gran_bytes, engine=engine,
@@ -376,7 +408,8 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                 return tuple(x[0] for x in _op_streams(d, *o))
 
             t, addr, wbit, val, scale = jax.vmap(one)(pairs)
-            fb, ch, row = decode_requests(addr, dram)   # one flat decode
+            with jax.named_scope(spans.DECODE):         # one flat decode
+                fb, ch, row = decode_requests(addr, dram)
             if engine in ("xla", "pallas"):
                 # batch-native: the block goes through one chunk scan
                 # ("xla") or one megakernel launch with the streams on
@@ -403,6 +436,7 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
             lambda p: replay_block(p, sdesign, M, N, K, ov, on, om), pairs)
         return stall.reshape(-1)[:n_pairs].reshape(-1, n_ops)[smap]
 
+    @jax.named_scope(spans.STAGES)
     def one_design(d, M, N, K, cnt, ov, on, om, velems, vcnt, trace_stall):
         mem = _mem(d)
         R, C = d["R"], d["C"]
@@ -503,6 +537,8 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
             in_axes=(0,) + (None,) * 9)(
                 design, M, N, K, cnt, ov, on, om, velems, vcnt)
 
+    fn.__name__ = fn.__qualname__ = _program_name(
+        dataflow, dram, runtime, mesh_shape, layout, with_sparsity, noc)
     return _SWEEP_FN_CACHE.setdefault(key, jax.jit(fn))
 
 
@@ -525,8 +561,26 @@ def _sweep_batched(cfgs: Sequence[AcceleratorConfig], ops: Sequence[Op],
 
     The caller (Study.plan) guarantees group-static flavor uniformity:
     every config shares dataflow, word_bytes, the core grid shape, the
-    layout fields (when enabled) and the sparse representation.
+    layout fields (when enabled) and the sparse representation.  One
+    `sweep` span covers the call, with its steps as child spans.
     """
+    with jax.profiler.TraceAnnotation(spans.SWEEP) as span:
+        with jax.profiler.TraceAnnotation(spans.SWEEP_COLUMNS):
+            fn, args, counts = _sweep_inputs(
+                cfgs, ops, dataflow, word_bytes, ert, mesh, dram, spec,
+                engine, core_index)
+        span.set_metadata(program=fn.__name__, designs=len(cfgs), **counts)
+        with jax.profiler.TraceAnnotation(spans.SWEEP_DISPATCH):
+            res = fn(*args)
+        with jax.profiler.TraceAnnotation(spans.SWEEP_FETCH):
+            return {k: np.asarray(v, np.float64)[:len(cfgs)]
+                    for k, v in res.items()}
+
+
+def _sweep_inputs(cfgs, ops, dataflow, word_bytes, ert, mesh, dram, spec,
+                  engine, core_index):
+    """(the group's sweep program, its arguments, the replay's `streams`,
+    `blocks` and `block`): the host side of `_sweep_batched`."""
     n = len(cfgs)
     f32 = np.float32
     ci = core_index
@@ -657,13 +711,20 @@ def _sweep_batched(cfgs: Sequence[AcceleratorConfig], ops: Sequence[Op],
             mesh, jax.sharding.PartitionSpec(tuple(mesh.axis_names)))
         design = {k: jax.device_put(v, sharding) for k, v in design.items()}
 
+    device_mesh = mesh if mesh is not None and mesh.size > 1 else None
     fn = _batched_design_fn(dataflow, word_bytes, ert, dram, spec,
                             engine=engine, mesh_shape=(Pr, Pc),
                             layout=layout_key, r_cap=r_cap,
                             representation=representation,
                             with_sparsity=with_sparsity, noc=noc_kind,
-                            device_mesh=(mesh if mesh is not None
-                                         and mesh.size > 1 else None))
-    res = fn(design, sdesign, smap_arr, M, N, K, cnt, ov, on, om,
-             velems, vcnt)
-    return {k: np.asarray(v, np.float64)[:n] for k, v in res.items()}
+                            device_mesh=device_mesh)
+    streams = len(sidx) * len(gemms) if dram is not None else 0
+    block = blocks = 0
+    if streams:
+        from ..trace.generator import DEFAULT_SPEC
+        block, blocks = _replay_blocks(
+            streams, (spec or DEFAULT_SPEC).cap,
+            1 if device_mesh is None else device_mesh.size)
+    return (fn, (design, sdesign, smap_arr, M, N, K, cnt, ov, on, om,
+                 velems, vcnt),
+            dict(streams=streams, blocks=blocks, block=block))

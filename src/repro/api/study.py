@@ -43,8 +43,10 @@ import json
 import os
 from typing import (Callable, Dict, List, Optional, Sequence, Tuple, Union)
 
+import jax
 import numpy as np
 
+from .. import spans
 from ..core import stages as st
 from ..core.accelerator import AcceleratorConfig, DramConfig
 from ..core.energy import DEFAULT_ERT, ERT, edp as _edp
@@ -884,13 +886,17 @@ class Study:
         setting is untouched).
         """
         cache_dir = cache if cache is not None else self._cache_dir
-        plan = self.plan()
-        errors: List[Dict[str, object]] = []
-        results, executed, hits = self._execute_cells(
-            plan, cache_dir=cache_dir, mesh=mesh, errors=errors)
-        res = self._frame(plan.cells,
-                          [results[i] for i in range(len(plan.cells))],
-                          executed, hits)
+        with jax.profiler.TraceAnnotation(spans.STUDY_RUN) as span:
+            with jax.profiler.TraceAnnotation(spans.STUDY_PLAN):
+                plan = self.plan()
+            span.set_metadata(cells=len(plan.cells), groups=len(plan.groups))
+            errors: List[Dict[str, object]] = []
+            results, executed, hits = self._execute_cells(
+                plan, cache_dir=cache_dir, mesh=mesh, errors=errors)
+            with jax.profiler.TraceAnnotation(spans.STUDY_FRAME):
+                res = self._frame(plan.cells, [results[i] for i in
+                                               range(len(plan.cells))],
+                                  executed, hits)
         if errors:
             res.meta["cell_errors"] = errors
         return res
@@ -936,12 +942,13 @@ class Study:
         hits = executed = 0
 
         if cache_dir is not None:
-            for i in sorted(sel):
-                hashes[i] = self._cell_hash(plan.cells[i])
-                got = self._cache_load(cache_dir, hashes[i])
-                if got is not None:
-                    results[i] = got
-                    hits += 1
+            with jax.profiler.TraceAnnotation(spans.STUDY_CACHE):
+                for i in sorted(sel):
+                    hashes[i] = self._cell_hash(plan.cells[i])
+                    got = self._cache_load(cache_dir, hashes[i])
+                    if got is not None:
+                        results[i] = got
+                        hits += 1
         loaded = set(results)
 
         def checkpoint(i: int) -> None:
@@ -991,56 +998,57 @@ class Study:
 
         # per-op engine fallback (and custom evaluators)
         pipelines: Dict[str, tuple] = {}
-        for i in plan.fallback:
-            if i not in sel or i in results:
-                continue
-            cell = plan.cells[i]
-            ops = self._workloads[cell.workload]
-            try:
-                if self._evaluator is not None:
-                    m = {k: float(v) for k, v in
-                         self._evaluator(cell.config, ops,
-                                         cell.fidelity).items()}
-                else:
-                    if cell.fidelity not in pipelines:
-                        pipelines[cell.fidelity] = st.build_pipeline(
-                            cell.fidelity, core_index=self._core_index,
-                            trace_spec=self._spec_for(cell.fidelity),
-                            engine=self._engine)
-                    rep = simulate_network(
-                        cell.config, ops, dram_fidelity=cell.fidelity,
-                        ert=self._ert,
-                        pipeline=pipelines[cell.fidelity])
-                    m = dict(total_cycles=rep.total_cycles,
-                             compute_cycles=rep.compute_cycles,
-                             stall_cycles=rep.stall_cycles,
-                             dram_bytes=rep.dram_bytes,
-                             energy_pj=rep.energy_pj,
-                             utilization=rep.utilization, edp=rep.edp,
-                             **energy_group_totals(rep.energy_breakdown))
-                    if (cell.config.noc.enabled
-                            and cell.config.num_cores > 1):
-                        m["noc_stall_cycles"] = rep.noc_stall_cycles
-                        m["noc_link_util"] = max(
-                            (o.noc_stats or {}).get("noc_link_util", 0.0)
-                            for o in rep.ops)
-                        m["allreduce_cycles"] = sum(
-                            (o.noc_stats or {}).get(
-                                "allreduce_cycles", 0.0)
-                            * o_count for o, o_count in
-                            zip(rep.ops, (op.count for op in ops)))
-            except ValueError:
-                raise    # invalid configuration: loud, never a failed cell
-            except Exception as e:  # noqa: BLE001 — one bad cell, study lives
-                results[i] = {"batched": 0.0, "cell_status": 1.0}
-                _record(errors, f"{cell.workload}/{cell.fidelity}/"
-                        f"{cell.design}", [i], e)
-                continue
-            m["batched"] = 0.0
-            results[i] = m
-            _flag_non_finite(results[i])
-            executed += 1
-            checkpoint(i)
+        with jax.profiler.TraceAnnotation(spans.STUDY_FALLBACK):
+            for i in plan.fallback:
+                if i not in sel or i in results:
+                    continue
+                cell = plan.cells[i]
+                ops = self._workloads[cell.workload]
+                try:
+                    if self._evaluator is not None:
+                        m = {k: float(v) for k, v in
+                             self._evaluator(cell.config, ops,
+                                             cell.fidelity).items()}
+                    else:
+                        if cell.fidelity not in pipelines:
+                            pipelines[cell.fidelity] = st.build_pipeline(
+                                cell.fidelity, core_index=self._core_index,
+                                trace_spec=self._spec_for(cell.fidelity),
+                                engine=self._engine)
+                        rep = simulate_network(
+                            cell.config, ops, dram_fidelity=cell.fidelity,
+                            ert=self._ert,
+                            pipeline=pipelines[cell.fidelity])
+                        m = dict(total_cycles=rep.total_cycles,
+                                 compute_cycles=rep.compute_cycles,
+                                 stall_cycles=rep.stall_cycles,
+                                 dram_bytes=rep.dram_bytes,
+                                 energy_pj=rep.energy_pj,
+                                 utilization=rep.utilization, edp=rep.edp,
+                                 **energy_group_totals(rep.energy_breakdown))
+                        if (cell.config.noc.enabled
+                                and cell.config.num_cores > 1):
+                            m["noc_stall_cycles"] = rep.noc_stall_cycles
+                            m["noc_link_util"] = max(
+                                (o.noc_stats or {}).get("noc_link_util", 0.0)
+                                for o in rep.ops)
+                            m["allreduce_cycles"] = sum(
+                                (o.noc_stats or {}).get(
+                                    "allreduce_cycles", 0.0)
+                                * o_count for o, o_count in
+                                zip(rep.ops, (op.count for op in ops)))
+                except ValueError:
+                    raise    # invalid configuration: loud, never a failed cell
+                except Exception as e:  # noqa: BLE001 — the study lives
+                    results[i] = {"batched": 0.0, "cell_status": 1.0}
+                    _record(errors, f"{cell.workload}/{cell.fidelity}/"
+                            f"{cell.design}", [i], e)
+                    continue
+                m["batched"] = 0.0
+                results[i] = m
+                _flag_non_finite(results[i])
+                executed += 1
+                checkpoint(i)
 
         return results, executed, hits
 

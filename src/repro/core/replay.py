@@ -92,6 +92,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .. import spans
 from .accelerator import DramConfig
 from .dram import row_buffer_latency
 
@@ -627,8 +628,9 @@ def replay_decoded(t_issue, flat_bank, ch, row, is_write, valid,
         _flat(is_write, False, bool), vf,
         _flat(core_id, 0, jnp.int32)))
 
-    pre, hits, misses, conflicts = _precompute_stream(
-        *xs, rowf, vf, cfg=cfg, busy=busy, n_cores=n_cores, n_qg=n_qg)
+    with jax.named_scope(spans.PRECOMPUTE):
+        pre, hits, misses, conflicts = _precompute_stream(
+            *xs, rowf, vf, cfg=cfg, busy=busy, n_cores=n_cores, n_qg=n_qg)
 
     carry0 = (jnp.zeros(batch + (ch_n * bk_n,), f32),
               jnp.zeros(batch + (ch_n,), f32),
@@ -641,8 +643,9 @@ def replay_decoded(t_issue, flat_bank, ch, row, is_write, valid,
     step = functools.partial(
         _chunk_step, cfg=cfg, busy=busy, max_passes=passes,
         tol=float(tol), n_cores=n_cores, n_qg=n_qg)
-    carry, (done, rt) = jax.lax.scan(
-        step, carry0, (xs[0], xs[1], xs[4], xs[5], xs[6], pre))
+    with jax.named_scope(spans.CHUNK_SCAN):
+        carry, (done, rt) = jax.lax.scan(
+            step, carry0, (xs[0], xs[1], xs[4], xs[5], xs[6], pre))
 
     def _unchunk(y):
         return jnp.moveaxis(y, 0, -2).reshape(batch + (nc * C,))[..., :n]

@@ -48,6 +48,7 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from ... import spans
 from ...core.accelerator import DramConfig
 from ...core.dram import row_buffer_latency
 
@@ -244,6 +245,7 @@ def iterate_fixed_point(one_pass, zero, *, cap: int, tol: float,
     def body_f(s):
         return (s[1], one_pass(s[1]), s[2] + 1)
 
+    @jax.named_scope(spans.ESCAPE)
     def _loop(dd):
         _, dn, _ = jax.lax.while_loop(cond_f, body_f,
                                       (dd[0], dd[1], jnp.int32(2)))
