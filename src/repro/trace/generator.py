@@ -24,6 +24,15 @@ the cap, the same trick `CycleDramStage` uses) makes the generators
 vmappable, which is what lets `Simulator.sweep` batch trace-fidelity
 design points instead of falling back to the per-op Python loop.
 
+The stream is built region by region (ifmap, filter, ofmap reads, ofmap
+writes) and then ordered by issue time with one batched stable sort
+that carries the payload: ties keep stream order, exactly as
+`np.argsort(kind="stable")`, and masked slots go last. A 4-way
+binary-search merge of the regions' sorted runs gives the same order,
+but on the TPU it runs as `while` loops of dynamic gathers; it took two
+thirds of a ViT-base trace sweep's device time on a TPU v5e, where the
+sort takes under 1 %.
+
 Conservation contract: `sum(valid) * gran_bytes * scale` equals the
 capacity-model byte total from `dataflow.dram_traffic` exactly — for
 self-scaled streams. A caller-supplied common scale (the contention
@@ -39,6 +48,7 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from ..core import dataflow as dfm
 from ..core.accelerator import AcceleratorConfig, DramConfig
@@ -120,41 +130,6 @@ _FAST_IS_ROW = {
 }
 
 
-def _merge_sort_order(key, region):
-    """Permutation that stably sorts `key`, given that `key` is
-    nondecreasing within each contiguous `region` segment.
-
-    The issue schedule is monotone per region (tau/frac only ever grow
-    with the within-region index, and masked slots get `_BIG_T`), so the
-    global sort is a 4-way stable merge of sorted runs: each element's
-    sorted position is its own within-region offset plus, per other
-    region, a binary-search count — ties resolved exactly as a stable
-    argsort would (earlier stream position first: `<=` against earlier
-    regions, `<` against later ones).  O(n log n) thin gather steps
-    instead of a full comparison sort, which dominates stream
-    generation time at sweep scale.
-    """
-    cap = key.shape[-1]
-    ii = jnp.arange(cap, dtype=jnp.int32)
-    rank = jnp.zeros(key.shape, jnp.int32)
-    for r in range(4):
-        # integer segment bounds of region r (`region` is nondecreasing)
-        s = jnp.searchsorted(region, r, side="left").astype(jnp.int32)
-        e = jnp.searchsorted(region, r + 1, side="left").astype(jnp.int32)
-        # pad outside the segment so the whole array is sorted: the
-        # -inf prefix keeps searchsorted counts offset by exactly `s`
-        seg = jnp.where(ii < s, -jnp.inf, jnp.where(ii >= e, jnp.inf, key))
-        lo = jnp.searchsorted(seg, key, side="left").astype(jnp.int32) - s
-        hi = jnp.searchsorted(seg, key, side="right").astype(jnp.int32) - s
-        n_r = e - s
-        contrib = jnp.where(region == r, ii - s,
-                            jnp.where(region > r, jnp.clip(hi, 0, n_r),
-                                      jnp.clip(lo, 0, n_r)))
-        rank = rank + contrib
-    return jnp.zeros(key.shape, jnp.int32).at[rank].set(ii,
-                                                        unique_indices=True)
-
-
 def _modmul(j, a, L):
     """mod(j * a, L) without forming the full product.
 
@@ -175,25 +150,12 @@ def _modmul(j, a, L):
     return jnp.mod(j_lo * a1 + j_hi * a64, L)
 
 
-@partial(jax.jit, static_argnames=("dataflow", "word_bytes", "spec"))
-def gemm_request_stream(dataflow: str, M, N, K, R, C, comp,
-                        ifmap_elems, filter_elems, ofmap_write_elems,
-                        ofmap_read_elems, word_bytes: int = 2,
-                        spec: TraceSpec = TraceSpec(), scale=None):
-    """Synthesize the demand-request stream for one GEMM op.
-
-    M/N/K/R/C/comp and the four region element counts (from
-    `dataflow.dram_traffic`, after any sparsity shrink) may be traced
-    arrays; `dataflow`, `word_bytes` and `spec` are static.
-
-    scale: optional stream-compression factor override. The multi-core
-    contention path passes one common scale so every core's stream is
-    compressed coherently; by default the op picks its own.
-
-    Returns (t_issue, addr, is_write, valid, scale) — arrays of shape
-    (spec.cap,), sorted by issue time, plus the scalar compression factor
-    (model stall * scale estimates the real stall).
-    """
+def _stream_in_region_order(dataflow: str, M, N, K, R, C, comp,
+                            ifmap_elems, filter_elems, ofmap_write_elems,
+                            ofmap_read_elems, word_bytes, spec, scale):
+    """`gemm_request_stream`'s (t_issue, addr, is_write, valid, scale)
+    before the sort: slots in region order (ifmap, filter, ofmap reads,
+    ofmap writes), each region's issue times nondecreasing."""
     f32 = jnp.float32
     wb = word_bytes
     gran = spec.gran_bytes
@@ -307,11 +269,42 @@ def gemm_request_stream(dataflow: str, M, N, K, R, C, comp,
     t = jnp.where(is_write, t_write,
                   jnp.where(region == R_OFMAP_RD, t_spill, t_read))
 
+    return t, addr, is_write, valid, scale
+
+
+@partial(jax.jit, static_argnames=("dataflow", "word_bytes", "spec"))
+def gemm_request_stream(dataflow: str, M, N, K, R, C, comp,
+                        ifmap_elems, filter_elems, ofmap_write_elems,
+                        ofmap_read_elems, word_bytes: int = 2,
+                        spec: TraceSpec = TraceSpec(), scale=None):
+    """Synthesize the demand-request stream for one GEMM op.
+
+    M/N/K/R/C/comp and the four region element counts (from
+    `dataflow.dram_traffic`, after any sparsity shrink) may be traced
+    arrays; `dataflow`, `word_bytes` and `spec` are static.
+
+    scale: optional stream-compression factor override. The multi-core
+    contention path passes one common scale so every core's stream is
+    compressed coherently; by default the op picks its own.
+
+    Returns (t_issue, addr, is_write, valid, scale) — arrays of shape
+    (spec.cap,), sorted by issue time, plus the scalar compression factor
+    (model stall * scale estimates the real stall).
+    """
+    t, addr, is_write, valid, scale = _stream_in_region_order(
+        dataflow, M, N, K, R, C, comp, ifmap_elems, filter_elems,
+        ofmap_write_elems, ofmap_read_elems, word_bytes, spec, scale)
     # ---- sort by issue time (invalid slots last) ---------------------------
-    # stable 4-way merge, not a full argsort: t is monotone per region
-    order = _merge_sort_order(jnp.where(valid, t, _BIG_T),
-                              region.astype(jnp.int32))
-    return (t[order], addr[order], is_write[order], valid[order], scale)
+    # One stable sort that carries the payload, with no gathers and no
+    # loop. On a TPU v5e it orders a block of 336 streams in ~1 ms more
+    # than generating them takes; the binary-search merge it replaced took
+    # ~1.5 s, and sorting an index then gathering the payload ~60 ms.
+    # `+ 0.0` maps -0.0 to +0.0, so the sort's total order ranks equal
+    # times as `<` does.
+    key = jnp.where(valid, t, _BIG_T) + 0.0
+    _, t, addr, is_write, valid = lax.sort(
+        (key, t, addr, is_write, valid), num_keys=1, is_stable=True)
+    return t, addr, is_write, valid, scale
 
 
 @partial(jax.jit, static_argnames=("dataflow", "dram_cfg", "word_bytes",
