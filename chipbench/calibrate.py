@@ -66,7 +66,7 @@ def reading(cell, seed: int, with_control: bool, devs, control) -> dict:
     import math
     from chipbench import check, harness
     from chipbench import designs as dz
-    wl = harness.Workload(cell, seed)
+    wl = harness.Workload(cell, seed, devs[:cell.chips])
     # the window's first Studies, as many as hold the cells a run compares
     n = math.ceil(cell.mix["check_sample"] / wl.n_designs)
     t0 = time.perf_counter()

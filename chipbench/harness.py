@@ -44,9 +44,14 @@ def cells_per_s(done: int, window_s: float) -> float:
 
 class Workload:
     """The cell's configuration and mix turned into Studies of the
-    program: Study `k` draws its designs from `(seed, k)`."""
+    program: Study `k` draws its designs from `(seed, k)`.
 
-    def __init__(self, cell: Cell, seed: int):
+    A mix with a `mesh` key (`{"shape": [4], "axes": ["data"]}`) runs
+    each Study over a mesh of the run's `devices`, whose size has to be
+    the cell's `chips`; a mix without one runs each Study as a user on
+    one device does, with no mesh."""
+
+    def __init__(self, cell: Cell, seed: int, devices):
         from repro.api import Study
         from repro.core.accelerator import AcceleratorConfig
         from repro.core.energy import ERT
@@ -63,6 +68,17 @@ class Workload:
         self.engine = mix["engine"]
         self.n_designs = len(mix["slots"])
         self._picks: Dict[int, list] = {}
+        self.mesh = None
+        if "mesh" in mix:
+            from repro.launch.mesh import auto_mesh
+            shape = tuple(int(n) for n in mix["mesh"]["shape"])
+            if math.prod(shape) != cell.chips or len(devices) != cell.chips:
+                raise ValueError(
+                    f"{cell.name}: a mesh of shape {shape} over "
+                    f"{len(devices)} devices for a cell of {cell.chips} "
+                    f"chips")
+            self.mesh = auto_mesh(shape, tuple(mix["mesh"]["axes"]),
+                                  devices=list(devices))
 
     def picks(self, k: int) -> list:
         if k not in self._picks:
@@ -78,7 +94,9 @@ class Workload:
                  .fidelity(self.fidelity)
                  .options(ert=self.ert, engine=self.engine,
                           trace_spec=self.spec))
-        return study.run()
+        if self.mesh is None:
+            return study.run()
+        return study.run(mesh=self.mesh)
 
 
 def answered(frame, picks) -> List[tuple]:
@@ -139,7 +157,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     comp = CompileLog()
     jax.monitoring.register_event_duration_secs_listener(comp)
 
-    wl = Workload(cell, seed)
+    wl = Workload(cell, seed, devices)
     t0 = time.perf_counter()
     warm = wl.run(dz.WARMUP)
     t_warm = time.perf_counter() - t0

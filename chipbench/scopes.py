@@ -418,7 +418,7 @@ def capture(cell, seed: int, seconds: float, out_dir: str) -> Dict:
     from . import harness
     enable_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    wl = harness.Workload(cell, seed)
+    wl = harness.Workload(cell, seed, jax.devices()[:cell.chips])
     wl.run(dz.WARMUP)
     with tempfile.TemporaryDirectory(prefix="chipbench-scopes-") as d:
         with tracing.capture(d):

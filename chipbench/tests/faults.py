@@ -1,13 +1,37 @@
-"""Faults planted under the timed path, for the tests of `correct`.
+"""Faults planted under the timed path, for the tests of `correct`, and
+the small cells (`tiny`) they are planted in.
 
-Each is a context manager that breaks one thing the program produces
-while a run of the harness drives it; the Study's compiled sweep
-programs are dropped on entry and exit so that the broken code is the
-code that runs."""
+Each fault is a context manager that breaks one thing the program
+produces while a run of the harness drives it; the Study's compiled
+sweep programs are dropped on entry and exit so that the broken code is
+the code that runs."""
 import contextlib
 import dataclasses
 
 import numpy as np
+
+from chipbench import spec
+
+
+def tiny(name, designs):
+    """Cell `name` on its first four GEMMs and its first `designs` slots,
+    or as many more as it takes for two of them to share a sweep program
+    (so that half of a program's batch can be left out)."""
+    from chipbench import designs as dz
+    cell = spec.find_cell(spec.load_benchmark(), name)
+    cell.config["gemms"] = cell.config["gemms"][:4]
+    slots = cell.mix["slots"]
+
+    def program(s):
+        # a fast sweep program does not split by DRAM
+        return dz.flavor(s) if cell.mix["fidelity"] == "trace" else (
+            s["dataflow"], s["layout_banks"])
+    while (designs < len(slots)
+           and len({program(s) for s in slots[:designs]}) == designs):
+        designs += 1
+    cell.mix["slots"] = slots[:designs]
+    cell.mix["check_sample"] = 16
+    return cell
 
 
 @contextlib.contextmanager
@@ -75,5 +99,24 @@ def answer_altered(fidelity=None):
     return _patched(simulator, "energy_pj", make)
 
 
+def no_exchange(fidelity=None):
+    """On a mesh, each device's share of a replay block is never
+    exchanged: the block's output is one device's share repeated, so
+    every row carries that device's stalls."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import PartitionSpec as P
+
+    def make(real):
+        def broken(f, *, mesh, out_specs, **k):
+            def local(*a):
+                return jnp.tile(f(*a), mesh.size)
+            return real(local, mesh=mesh, out_specs=P(), **k)
+        return broken
+    return _patched(jax, "shard_map", make)
+
+
 FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,
           "answer_altered": answer_altered}
+# faults that exist only across chips
+MESH_FAULTS = {"no_exchange": no_exchange}

@@ -16,15 +16,6 @@ SEED = 2**31 + 4099
 CELLS = spec.load_benchmark()["workloads"]
 
 
-def tiny(name, designs):
-    """The cell's first `designs` slots on its first four GEMMs."""
-    cell = spec.find_cell(spec.load_benchmark(), name)
-    cell.config["gemms"] = cell.config["gemms"][:4]
-    cell.mix["slots"] = cell.mix["slots"][:designs]
-    cell.mix["check_sample"] = 16
-    return cell
-
-
 def run(cell):
     return harness.run_cell(cell, SEED, 0.2, False, jax.devices()[:1],
                             time.perf_counter())
@@ -33,7 +24,7 @@ def run(cell):
 @pytest.mark.parametrize("name", [w["name"] for w in CELLS
                                   if w["chips"] == 1])
 def test_sound_run_is_correct_and_each_fault_is_not(name):
-    cell = tiny(name, 4)
+    cell = faults.tiny(name, 4)
     r = run(cell)
     assert r["correct"], r["checks"]
     assert r["failed"] == 0 and r["attempted"] >= 4
