@@ -68,29 +68,28 @@ def reading(cell, seed: int, with_control: bool, devs, control) -> dict:
     from chipbench import designs as dz
     wl = harness.Workload(cell, seed, devs[:cell.chips])
     # the window's first Studies, as many as hold the cells a run compares
-    n = math.ceil(cell.mix["check_sample"] / wl.n_designs)
+    n = math.ceil(cell.mix["check_sample"] / wl.n_cells)
     t0 = time.perf_counter()
     frames = [(wl.run(k), wl.picks(k)) for k in range(n)]
     t1 = time.perf_counter()
-    _, bad, cells = harness.tally(frames, wl.n_designs, wl.engine)
+    _, bad, cells = harness.tally(frames, wl.n_cells, wl.engine)
     pick = check.draw_sample([r["total_cycles"] for _, r in cells],
                              cell.mix["check_sample"], seed)
     line = {"workload": cell.name, "seed": seed, "studies": n,
             "study_s": (t1 - t0) / n, "bad_cells": bad,
-            "program": check.readings(cells, pick, cell.config,
-                                      wl.fidelity)}
+            "program": check.readings(cells, pick, cell.config)}
     t2 = time.perf_counter()
     line["reference_s"] = t2 - t1
     if with_control:
         # the control in the program's place, on the same sample
         ctl_cells = list(cells)
         for i in pick:
-            design, _ = cells[i]
-            ctl_cells[i] = (design, control(dz.plain(design),
-                                            cell.config["gemms"],
-                                            wl.fidelity, cell.config))
-        line["control"] = check.readings(ctl_cells, pick, cell.config,
-                                         wl.fidelity)
+            design, row = cells[i]
+            fid = str(row["fidelity"])
+            ctl_cells[i] = (design, dict(
+                control(dz.plain(design), cell.config["gemms"], fid,
+                        cell.config), fidelity=fid))
+        line["control"] = check.readings(ctl_cells, pick, cell.config)
         line["control_s"] = time.perf_counter() - t2
     return line
 

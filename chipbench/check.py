@@ -17,7 +17,8 @@ What the window's Studies produced is compared with the plain reference
                 DRAM stall feeds).
 
 The sample holds the cell with the most total cycles and the rest drawn
-from the seed; a design met twice is computed once.
+from the seed; each row is held to the reference at its own fidelity,
+and a design met twice at one fidelity is computed once.
 """
 from __future__ import annotations
 
@@ -83,17 +84,19 @@ def draw_sample(totals: Sequence[float], n: int, seed: int) -> List[int]:
 
 
 def readings(cells: List[Tuple[Dict, Dict[str, float]]],
-             pick: Sequence[int], cfg: Dict, fidelity: str,
+             pick: Sequence[int], cfg: Dict,
              reference: Callable = ref.cell_metrics) -> Dict[str, float]:
     """(analytic_err, cycles_err) over the picked cells; `cells` is every
-    answered cell as (design, frame row)."""
+    answered cell as (design, frame row), and each row names its
+    `fidelity`."""
     if not pick:
         return {"analytic_err": NO_READING, "cycles_err": NO_READING}
     worst = {"analytic_err": 0.0, "cycles_err": 0.0}
     memo: Dict[str, Dict[str, float]] = {}
     for i in pick:
         design, row = cells[i]
-        key = json.dumps(design, sort_keys=True)
+        fidelity = str(row["fidelity"])
+        key = json.dumps([fidelity, design], sort_keys=True)
         if key not in memo:
             memo[key] = reference(dz.plain(design), cfg["gemms"], fidelity,
                                   cfg)

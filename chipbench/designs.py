@@ -31,10 +31,11 @@ def sram_axis(cfg: Dict) -> List[int]:
     return sorted(set(raw))
 
 
-def design(cfg: Dict, slot: Dict, sram_kb: int) -> Dict:
+def design(cfg: Dict, slot: Dict, sram_kb: float) -> Dict:
     """One design as a plain nested dict (the fields of an accelerator
     config): the template with the slot's fields and `sram_kb` KiB of
-    operand SRAM split in thirds."""
+    operand SRAM split in thirds, each the floor of a third of the whole
+    bytes (409.6 KiB, 0.4 MiB, gives 139,810 B an operand)."""
     if cfg["design_space"]["sram_split"] != "thirds":
         raise ValueError(
             f"unknown SRAM split {cfg['design_space']['sram_split']!r}")
@@ -42,7 +43,7 @@ def design(cfg: Dict, slot: Dict, sram_kb: int) -> Dict:
     d["dataflow"] = slot["dataflow"]
     d["cores"] = [dict(d["cores"][0], rows=slot["array"],
                        cols=slot["array"])]
-    third = int(sram_kb) * 1024 // 3
+    third = int(float(sram_kb) * 1024) // 3
     d["memory"].update(ifmap_sram_bytes=third, filter_sram_bytes=third,
                        ofmap_sram_bytes=third)
     d["dram"].update(channels=slot["channels"],
@@ -81,5 +82,5 @@ def draw(cfg: Dict, mix: Dict, seed: int, study: int) -> List[Dict]:
     for idx in groups.values():
         kbs = rng.choice(mix["sram_kb_pool"], size=len(idx), replace=False)
         for i, kb in zip(idx, kbs):
-            out[i] = design(cfg, mix["slots"][i], int(kb))
+            out[i] = design(cfg, mix["slots"][i], kb)
     return out

@@ -42,14 +42,23 @@ def cells_per_s(done: int, window_s: float) -> float:
     return done / window_s if window_s > 0 else 0.0
 
 
+def fidelities(mix: Dict) -> tuple:
+    """The fidelities of a mix's Studies: `"fidelity"` is one name or a
+    list of them."""
+    fid = mix["fidelity"]
+    return (fid,) if isinstance(fid, str) else tuple(fid)
+
+
 class Workload:
     """The cell's configuration and mix turned into Studies of the
-    program: Study `k` draws its designs from `(seed, k)`.
+    program: Study `k` draws its designs from `(seed, k)` and runs each
+    at every fidelity of the mix, `n_cells` cells in all.
 
     A mix with a `mesh` key (`{"shape": [4], "axes": ["data"]}`) runs
     each Study over a mesh of the run's `devices`, whose size has to be
     the cell's `chips`; a mix without one runs each Study as a user on
-    one device does, with no mesh."""
+    one device does, with no mesh, even where the cell holds more chips
+    (a whole host, for steadiness)."""
 
     def __init__(self, cell: Cell, seed: int, devices):
         from repro.api import Study
@@ -64,9 +73,9 @@ class Workload:
                        float(g[4])) for g in cfg["gemms"]]
         self.ert = ERT(**cfg["ert"])
         self.spec = TraceSpec(**cfg["trace_spec"])
-        self.fidelity = mix["fidelity"]
+        self.fidelities = fidelities(mix)
         self.engine = mix["engine"]
-        self.n_designs = len(mix["slots"])
+        self.n_cells = len(mix["slots"]) * len(self.fidelities)
         self._picks: Dict[int, list] = {}
         self.mesh = None
         if "mesh" in mix:
@@ -91,7 +100,7 @@ class Workload:
                  .designs([self.Config.from_dict(d) for d in picks],
                           [f"d{j}" for j in range(len(picks))])
                  .workloads({self.cfg["workload"]: self.ops})
-                 .fidelity(self.fidelity)
+                 .fidelity(*self.fidelities)
                  .options(ert=self.ert, engine=self.engine,
                           trace_spec=self.spec))
         if self.mesh is None:
@@ -109,14 +118,15 @@ def answered(frame, picks) -> List[tuple]:
     return out
 
 
-def tally(frames, n_designs: int, engine: str):
+def tally(frames, n_cells: int, engine: str):
     """(attempted, bad, answered cells) over the window's Study frames,
-    each given with its design picks: a failed or missing cell counts as
-    attempted and not answered."""
+    each given with its design picks and expected to hold `n_cells`
+    cells: a failed or missing cell counts as attempted and not
+    answered."""
     attempted, bad, cells = 0, 0, []
     for frame, picks in frames:
-        attempted += n_designs
-        bad += check.count_bad(frame, n_designs, engine)
+        attempted += n_cells
+        bad += check.count_bad(frame, n_cells, engine)
         cells += answered(frame, picks)
     return attempted, bad, cells
 
@@ -184,7 +194,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
                for d in devices)
 
     attempted, bad, cells = tally([(res, wl.picks(k)) for k, res in frames],
-                                  wl.n_designs, wl.engine)
+                                  wl.n_cells, wl.engine)
     done = len(cells)
     compiles = [[t - start, secs] for t, secs in comp.events]
     window_compiles = len(comp.between(start, end))
@@ -225,7 +235,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool, devices,
     pick = check.draw_sample([row["total_cycles"] for _, row in cells],
                              cell.mix["check_sample"], seed)
     values = {"bad_cells": bad,
-              **check.readings(cells, pick, cell.config, wl.fidelity)}
+              **check.readings(cells, pick, cell.config)}
     checks, correct = check.judge(values, cell.limits)
     log(f"reference compared in {time.perf_counter() - t1:.2f} s")
     return {"correct": correct, "attempted": attempted,

@@ -428,7 +428,7 @@ def capture(cell, seed: int, seconds: float, out_dir: str) -> Dict:
         with open(path, "rb") as f:
             data = f.read()
     _, _, cells = harness.tally([(res, wl.picks(k)) for k, res in frames],
-                                wl.n_designs, wl.engine)
+                                wl.n_cells, wl.engine)
     os.makedirs(out_dir, exist_ok=True)
     with gzip.open(os.path.join(out_dir, f"{cell.name}.xplane.pb.gz"),
                    "wb") as f:

@@ -13,21 +13,31 @@ import numpy as np
 from chipbench import spec
 
 
+def _program(mix, slot):
+    """The widest sweep program of the mix that a slot's design joins: a
+    fast program does not split by DRAM, a trace program does."""
+    from chipbench import designs as dz
+    from chipbench.harness import fidelities
+    if "fast" in fidelities(mix):
+        return slot["dataflow"], slot["layout_banks"]
+    return dz.flavor(slot)
+
+
+def shares_a_program(mix):
+    """Whether two of the mix's designs share a sweep program."""
+    programs = [_program(mix, s) for s in mix["slots"]]
+    return len(set(programs)) < len(programs)
+
+
 def tiny(name, designs):
     """Cell `name` on its first four GEMMs and its first `designs` slots,
     or as many more as it takes for two of them to share a sweep program
     (so that half of a program's batch can be left out)."""
-    from chipbench import designs as dz
     cell = spec.find_cell(spec.load_benchmark(), name)
     cell.config["gemms"] = cell.config["gemms"][:4]
     slots = cell.mix["slots"]
-
-    def program(s):
-        # a fast sweep program does not split by DRAM
-        return dz.flavor(s) if cell.mix["fidelity"] == "trace" else (
-            s["dataflow"], s["layout_banks"])
     while (designs < len(slots)
-           and len({program(s) for s in slots[:designs]}) == designs):
+           and not shares_a_program(dict(cell.mix, slots=slots[:designs]))):
         designs += 1
     cell.mix["slots"] = slots[:designs]
     cell.mix["check_sample"] = 16
@@ -49,9 +59,19 @@ def _patched(module, name, make):
         simulator._SWEEP_FN_CACHE.update(saved)
 
 
+@contextlib.contextmanager
 def state_unchanged(fidelity):
-    """The DRAM model never advances: the replay (trace) or the stall
-    stage (fast) hands back no stall."""
+    """The DRAM model never advances at any of the mix's fidelities
+    (`fidelity` is one name or a list of them)."""
+    from chipbench.harness import fidelities
+    with contextlib.ExitStack() as stack:
+        for fid in fidelities({"fidelity": fidelity}):
+            stack.enter_context(_no_stall(fid))
+        yield
+
+
+def _no_stall(fidelity):
+    """The replay (trace) or the stall stage (fast) hands back no stall."""
     if fidelity == "trace":
         from repro.core import dram
 
@@ -87,6 +107,34 @@ def half_batch(fidelity=None):
     return _patched(study, "_sweep_batched", make)
 
 
+def half_designs(fidelity=None):
+    """Half of each Study's designs left out: their cells read the mean
+    of the rest's at the same fidelity.  The form of `half_batch` for a
+    mix whose every sweep program holds one design."""
+    from repro.api import study
+
+    def make(real):
+        def broken(self, plan, indices=None, **k):
+            keep = {label for label, _ in
+                    self._designs[:max(1, len(self._designs) // 2)]}
+            sel = None if indices is None else set(indices)
+            want = [c.index for c in plan.cells
+                    if sel is None or c.index in sel]
+            results, executed, hits = real(
+                self, plan, [i for i in want if plan.cells[i].design in keep],
+                **k)
+            for i in want:
+                if i not in results:
+                    peers = [r for j, r in results.items()
+                             if plan.cells[j].fidelity
+                             == plan.cells[i].fidelity]
+                    results[i] = {m: float(np.mean([r[m] for r in peers]))
+                                  for m in peers[0]}
+            return results, executed, hits
+        return broken
+    return _patched(study.Study, "_execute_cells", make)
+
+
 def answer_altered(fidelity=None):
     """Every cell's energy 1 % off where the sweep kernel produces it."""
     from repro.api import simulator
@@ -120,3 +168,13 @@ FAULTS = {"state_unchanged": state_unchanged, "half_batch": half_batch,
           "answer_altered": answer_altered}
 # faults that exist only across chips
 MESH_FAULTS = {"no_exchange": no_exchange}
+
+
+def for_cell(mix):
+    """The faults a cell without a mesh can have: where no two designs
+    share a sweep program there is no half of a program's batch to leave
+    out, and half of the Study's designs are left out in its place."""
+    if shares_a_program(mix):
+        return dict(FAULTS)
+    return {k: (half_designs if k == "half_batch" else v)
+            for k, v in FAULTS.items()}

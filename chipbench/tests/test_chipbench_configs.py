@@ -7,7 +7,7 @@ import shutil
 
 import pytest
 
-from chipbench import spec
+from chipbench import harness, spec
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -35,6 +35,34 @@ def test_design_template_is_the_table_v_corner_preset():
     assert cfg["design_template"] == get_preset("table-v-corner").to_dict()
 
 
+def _config(name):
+    with open(os.path.join(spec.HERE, "configs", name + ".json")) as f:
+        return json.load(f)
+
+
+def test_resnet18_dram_is_the_registered_flip_studys_input():
+    from dataclasses import asdict
+    from repro.core.accelerator import tpu_like_config
+    from repro.core.energy import ERT
+    from repro.core.workloads import resnet18, resnet18_six_layers
+    from repro.trace.generator import TraceSpec
+    cfg = _config("resnet18-dram")
+    ops = resnet18_six_layers()
+    assert [tuple(g) for g in cfg["gemms"]] == [
+        (o.name, o.M, o.N, o.K, o.count) for o in ops]
+    assert all(o.kind == "gemm" and o.sparsity_nm is None for o in ops)
+    # the depth is the one cut: the first 6 of ResNet-18's 21 GEMMs
+    assert cfg["reduced"] == ["num_gemms"]
+    assert cfg["num_gemms"] == len(cfg["gemms"]) == 6
+    assert cfg["source_num_gemms"] == len(resnet18()) == 21
+    assert cfg["design_template"] == tpu_like_config(
+        array=32, dataflow="ws", sram_mb=0.4).to_dict()
+    assert cfg["design_template"]["memory"]["ifmap_sram_bytes"] == 139810
+    assert cfg["ert"] == asdict(ERT())
+    assert cfg["trace_spec"] == asdict(TraceSpec())
+    assert cfg["precision"] == "float32"
+
+
 def test_design_template_builds_a_config_with_explicit_fields(bench):
     from repro.core.accelerator import AcceleratorConfig
     for c in bench["configs"]:
@@ -51,7 +79,9 @@ def test_every_cell_finds_its_files_by_name(bench):
         assert cell.config["name"] == w["config"]
         assert set(cell.limits) >= {"bad_cells", "analytic_err",
                                     "cycles_err"}
-        assert cell.mix["fidelity"] in ("fast", "trace")
+        fids = harness.fidelities(cell.mix)
+        assert fids and set(fids) <= {"fast", "trace"}
+        assert len(set(fids)) == len(fids)
         for m in cell.per_layer:
             assert callable(spec.reader(m["name"]))
 

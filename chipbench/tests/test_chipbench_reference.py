@@ -4,7 +4,7 @@ import ml_dtypes
 import numpy as np
 import pytest
 
-from chipbench import check, designs as dz
+from chipbench import check, designs as dz, harness
 from chipbench import reference as ref
 from chipbench import spec
 
@@ -35,12 +35,13 @@ def test_control_in_bfloat16_fails_the_limits(name):
     cell = spec.find_cell(spec.load_benchmark(), name)
     cell.config["gemms"] = cell.config["gemms"][:4]
     designs = _designs(cell, cell.mix["slots"][:6])
-    fid = cell.mix["fidelity"]
-    cells = [(d, ref.cell_metrics(dz.plain(d), cell.config["gemms"], fid,
-                                  cell.config, num=ml_dtypes.bfloat16))
-             for d in designs]
+    cells = [(d, dict(ref.cell_metrics(dz.plain(d), cell.config["gemms"],
+                                       fid, cell.config,
+                                       num=ml_dtypes.bfloat16),
+                      fidelity=fid))
+             for fid in harness.fidelities(cell.mix) for d in designs]
     values = {"bad_cells": 0, **check.readings(
-        cells, range(len(cells)), cell.config, fid)}
+        cells, range(len(cells)), cell.config)}
     checks, correct = check.judge(values, cell.limits)
     assert not correct, checks
 
@@ -66,7 +67,7 @@ def test_reference_matches_the_program_on_a_small_study():
         rows = res.filter(fidelity=fid).rows()
         cells = [(designs[int(r["design"][1:])], r) for r in rows]
         assert len(cells) == len(designs)
-        got = check.readings(cells, range(len(cells)), cfg, fid)
+        got = check.readings(cells, range(len(cells)), cfg)
         assert got["analytic_err"] < 1e-5
         assert got["cycles_err"] < (1e-5 if fid == "fast" else
                                     cell.limits["cycles_err"])

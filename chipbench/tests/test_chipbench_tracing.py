@@ -22,14 +22,17 @@ def _events(meta, evs):
     return md, ev
 
 
-def _trace(host, device):
+def _trace(host, device, idle_devices=0):
     hm, he = _events(sorted({n for n, _, _ in host}), host)
     dm, de = _events(sorted({n for n, _, _ in device}), device)
+    idle = "".join(f'planes {{ id: {3 + i} name: "/device:TPU:{1 + i}" '
+                   f'lines {{ id: 1 name: "XLA Ops" timestamp_ns: 0 }} }}\n'
+                   for i in range(idle_devices))
     return ProfileData.from_text_proto(
         f'planes {{ id: 1 name: "/host:CPU" lines {{ id: 1 name: "python" '
         f'timestamp_ns: 0 {he} }} {hm} }}\n'
         f'planes {{ id: 2 name: "/device:TPU:0" lines {{ id: 1 '
-        f'name: "XLA Ops" timestamp_ns: 0 {de} }} {dm} }}')
+        f'name: "XLA Ops" timestamp_ns: 0 {de} }} {dm} }}\n' + idle)
 
 
 def test_union_covered_and_gaps():
@@ -66,6 +69,21 @@ def test_reduce_on_a_synthetic_trace():
     assert spec.reader("device_busy_ms_per_cell")(rec) == pytest.approx(
         40 * us / 4 * 1e3)
     assert spec.reader("device_idle_share")(rec) == pytest.approx(0.6)
+
+
+def test_devices_that_ran_nothing_are_not_averaged_in():
+    host = [("window", 0, 100), ("study", 10, 80)]
+    device = [("fusion.1", 10, 30), ("while.2", 50, 20)]
+    one = tracing.reduce(_trace(host, device))
+    held = tracing.reduce(_trace(host, device, idle_devices=3))
+    assert held["devices"] == one["devices"] == 1
+    assert held["busy_s"] == one["busy_s"] == [pytest.approx(50e-6)]
+    assert held["gap_s"] == one["gap_s"] and held["op_s"] == one["op_s"]
+    rec = {"cells": 4, "trace": held}
+    assert spec.reader("device_idle_share")(rec) == pytest.approx(0.5)
+    # where no device ran anything, every device reads idle
+    none = tracing.reduce(_trace(host, [], idle_devices=3))
+    assert none["busy_s"] == [0.0] * 4
 
 
 def test_readers_find_nothing_without_a_trace():

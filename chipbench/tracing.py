@@ -6,8 +6,10 @@ benchmark marks it with a `window` span and each Study with a `study`
 span (`jax.profiler.TraceAnnotation`), so host spans and device
 operations share the profiler's clock.  `reduce` reads:
 
-  busy_s           per device, the union of its operations' intervals
-                   inside the window
+  busy_s           per device used, the union of its operations'
+                   intervals inside the window; a device that ran no
+                   operation (a cell that holds a host of chips for
+                   steadiness and runs on one) is not among them
   idle_in_studies  per device, the part of the `study` spans in which no
                    operation ran on it
   op_s             time per operation name inside the window, every
@@ -148,7 +150,8 @@ def reduce(pd) -> Optional[Dict]:
     no `window` span."""
     host = _host_events(pd)
     windows = [(s, e) for n, s, e in host if n == WINDOW]
-    devices = _device_planes(pd)
+    planes = _device_planes(pd)
+    devices = [p for p in planes if _op_events(p)] or planes
     if not windows or not devices:
         return None
     lo, hi = windows[-1]
