@@ -27,6 +27,7 @@ from ..core import stages as st
 from ..core.accelerator import (AcceleratorConfig, DramConfig, MemoryConfig,
                                 SparsityConfig)
 from ..core.energy import DEFAULT_ERT, ERT, energy_pj
+from ..core.layout import streaming_slowdown_table
 from ..core.engine import (_ENERGY_GROUPS, NetworkReport, OpResult,
                            simulate_network, simulate_op)
 from ..core.workloads import PAPER_WORKLOADS, Op
@@ -309,7 +310,12 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
     fields shaping the conflict model; None skips the layout math
     entirely — the plan groups enabled and disabled cells separately),
     `r_cap` (static bound on array rows for the layout window) and the
-    sparse metadata `representation`.  `device_mesh` (a device mesh of
+    sparse metadata `representation`.  With layout on, the program
+    computes the layout slowdown curve once per distinct (array rows, op
+    stride) pair (`lay`: the pairs' rows and strides, the `lay_row`
+    design column and each op's stride index) and every design and op
+    reads its own; the curve does not depend on the compute window, so
+    this is exact.  `device_mesh` (a device mesh of
     more than one device, or None) splits the trace replay over devices.
 
     With `dram` set (trace fidelity), the first-order stall is replaced by
@@ -437,13 +443,19 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
         return stall.reshape(-1)[:n_pairs].reshape(-1, n_ops)[smap]
 
     @jax.named_scope(spans.STAGES)
-    def one_design(d, M, N, K, cnt, ov, on, om, velems, vcnt, trace_stall):
+    def one_design(d, M, N, K, cnt, ov, on, om, velems, vcnt, trace_stall,
+                   curves=None):
         mem = _mem(d)
         R, C = d["R"], d["C"]
         sp, mc = _features(d, ov, on, om)
-        lay = None if layout is None else dict(cfg=layout, r_cap=r_cap)
+        sd = None
+        if curves is not None:
+            # each op's curve: the design's row of the program's table,
+            # at the op's stride
+            table, op_stride = curves
+            sd = table[d["lay_row"].astype(jnp.int32)][op_stride]
         s = st.traced_op_stats(dataflow, M, N, K, R, C, mem, d["bw"],
-                               sparsity=sp, multicore=mc, layout=lay)
+                               sparsity=sp, multicore=mc, layout_sd=sd)
         stall_per_op = s["stall_cycles"] if trace_stall is None else \
             trace_stall
         comp_t = s["compute_cycles"] * cnt
@@ -525,15 +537,24 @@ def _batched_design_fn(dataflow: str, word_bytes: int, ert: ERT,
                     energy_pj=energy, utilization=util, **groups,
                     **noc_cols)
 
-    def fn(design, sdesign, smap, M, N, K, cnt, ov, on, om, velems, vcnt):
+    def fn(design, sdesign, smap, M, N, K, cnt, ov, on, om, velems, vcnt,
+           lay):
+        curves = None
+        if layout is not None:
+            # one slowdown curve per distinct (array rows, op stride) of
+            # the group, shared by every design and op that has it
+            with jax.named_scope(spans.STAGES):
+                curves = (streaming_slowdown_table(
+                    layout, lay["rows"], lay["strides"], word_bytes,
+                    r_cap=r_cap), lay["op_stride"])
+        one = functools.partial(one_design, curves=curves)
         if dram is not None:
             stall = _trace_stalls(sdesign, smap, M, N, K,
                                   ov, on, om)          # (designs, ops)
-            return jax.vmap(one_design,
-                            in_axes=(0,) + (None,) * 9 + (0,))(
+            return jax.vmap(one, in_axes=(0,) + (None,) * 9 + (0,))(
                 design, M, N, K, cnt, ov, on, om, velems, vcnt, stall)
         return jax.vmap(
-            functools.partial(one_design, trace_stall=None),
+            functools.partial(one, trace_stall=None),
             in_axes=(0,) + (None,) * 9)(
                 design, M, N, K, cnt, ov, on, om, velems, vcnt)
 
@@ -580,7 +601,8 @@ def _sweep_batched(cfgs: Sequence[AcceleratorConfig], ops: Sequence[Op],
 def _sweep_inputs(cfgs, ops, dataflow, word_bytes, ert, mesh, dram, spec,
                   engine, core_index):
     """(the group's sweep program, its arguments, the replay's `streams`,
-    `blocks` and `block`): the host side of `_sweep_batched`."""
+    `blocks` and `block`, and `layout_rows`, the layout slowdown curves
+    the program computes): the host side of `_sweep_batched`."""
     n = len(cfgs)
     f32 = np.float32
     ci = core_index
@@ -693,6 +715,22 @@ def _sweep_inputs(cfgs, ops, dataflow, word_bytes, ert, mesh, dram, spec,
         cols["noc_bw"] = [c.noc.link_bandwidth_bytes_per_cycle for c in cfgs]
         cols["noc_flit"] = [c.noc.flit_bytes for c in cfgs]
         cols["noc_buf"] = [c.noc.buffer_flits for c in cfgs]
+    # The layout stage's per-cycle slowdown depends only on the array rows
+    # and the op's stride (N), not on the design's compute window: the
+    # program computes one curve per distinct (rows, stride) and each
+    # design and op reads its own (`lay_row` design column, `op_stride`).
+    lay = None
+    if with_layout:
+        rows = sorted({c.cores[ci].rows for c in cfgs})
+        cols["lay_row"] = [rows.index(c.cores[ci].rows) for c in cfgs]
+        # the stride as the kernel sees it: max(1, N) in float32, to int32
+        op_n = np.maximum(f32(1.0), np.asarray([o.N for o in gemms], f32))
+        strides, op_stride = np.unique(op_n.astype(np.int32),
+                                       return_inverse=True)
+        lay = dict(rows=jnp.asarray(rows, jnp.int32),
+                   strides=jnp.asarray(strides, jnp.int32),
+                   op_stride=jnp.asarray(op_stride.reshape(-1), jnp.int32))
+    layout_rows = 0 if lay is None else len(rows) * len(strides)
     sdesign = smap_arr = None
     if dram is not None:
         sdesign = {k: jnp.asarray([cols[k][i] for i in sidx], f32)
@@ -726,5 +764,6 @@ def _sweep_inputs(cfgs, ops, dataflow, word_bytes, ert, mesh, dram, spec,
             streams, (spec or DEFAULT_SPEC).cap,
             1 if device_mesh is None else device_mesh.size)
     return (fn, (design, sdesign, smap_arr, M, N, K, cnt, ov, on, om,
-                 velems, vcnt),
-            dict(streams=streams, blocks=blocks, block=block))
+                 velems, vcnt, lay),
+            dict(streams=streams, blocks=blocks, block=block,
+                 layout_rows=layout_rows))

@@ -94,7 +94,9 @@ def _distinct_slowdown(line, bank, num_banks: int, ports: int):
     line = line.astype(jnp.int32)
     bank = bank.astype(jnp.int32)
     stride = jnp.max(line) + 1
-    key = jnp.sort(bank * stride + line, axis=1)
+    # lax.sort, not jnp.sort: the same stable sort, emitted in place so
+    # it carries the caller's named scope (jnp.sort is a jitted call)
+    key = jax.lax.sort(bank * stride + line, dimension=1)
     new = jnp.concatenate(
         [jnp.ones_like(key[:, :1], bool), key[:, 1:] != key[:, :-1]], axis=1)
     b = key // stride
@@ -105,24 +107,21 @@ def _distinct_slowdown(line, bank, num_banks: int, ports: int):
     return jnp.maximum(1, per_bank.max(axis=1))
 
 
-def streaming_layout_extra(cfg: LayoutConfig, R, comp, elem_stride,
-                           word_bytes: int = 2, *, r_cap: int = None,
-                           lead_stride: int = 1):
-    """Extra cycles a systolic streaming pattern loses to bank conflicts.
+def streaming_slowdown_curve(cfg: LayoutConfig, R, elem_stride,
+                             word_bytes: int = 2, *, r_cap: int,
+                             lead_stride: int = 1):
+    """Per-cycle slowdown (int32, `STREAM_WINDOW_CYCLES` long) of a
+    systolic streaming pattern: cycle t reads the R elements
+    {t * lead_stride + r * elem_stride}.
 
-    The traced twin of the LayoutStage model, shared by the per-op oracle
-    pipeline and the batched sweep kernel so both paths agree bit-for-bit:
-    `R`, `comp` and `elem_stride` may be traced scalars; the LayoutConfig
-    fields, `r_cap` (static bound on R — rows beyond R are masked by
-    duplicating the r=0 access, which adds no distinct (bank, line) pair)
-    and the `STREAM_WINDOW_CYCLES` window are static. Cycles past
-    clip(floor(comp), 8, window) are masked out of the mean, reproducing
-    the op-sized window of the eager model exactly.
+    `R` and `elem_stride` may be traced scalars; the LayoutConfig fields,
+    `word_bytes`, `lead_stride` and `r_cap` (static bound on R — rows
+    beyond R are masked by duplicating the r=0 access, which adds no
+    distinct (bank, line) pair) are static. The curve does not depend on
+    the op's compute window, so a batch of ops that share (R, stride)
+    shares one curve (the sweep kernel computes one per distinct pair).
     """
-    if r_cap is None:
-        r_cap = int(R)
-    n_cyc = STREAM_WINDOW_CYCLES
-    t = jnp.arange(n_cyc, dtype=jnp.int32)
+    t = jnp.arange(STREAM_WINDOW_CYCLES, dtype=jnp.int32)
     r = jnp.arange(r_cap, dtype=jnp.int32)
     # integer index grid: element offsets stay exact past f32's 2^24
     # (large-vocab GEMMs stream with strides in the 100k+ range)
@@ -132,10 +131,52 @@ def streaming_layout_extra(cfg: LayoutConfig, R, comp, elem_stride,
     rvalid = r[None, :] < R
     line = jnp.where(rvalid, line, line[:, :1])
     bank = jnp.where(rvalid, bank, bank[:, :1])
-    sd = _distinct_slowdown(line, bank, cfg.num_banks, cfg.ports_per_bank)
+    return _distinct_slowdown(line, bank, cfg.num_banks, cfg.ports_per_bank)
+
+
+def streaming_slowdown_table(cfg: LayoutConfig, rows, strides,
+                             word_bytes: int = 2, *, r_cap: int):
+    """(len(rows), len(strides), window) int32: the slowdown curve of
+    every (R, stride) pair of the distinct array rows and op strides."""
+    def curve(R, stride):
+        return streaming_slowdown_curve(cfg, R, stride, word_bytes,
+                                        r_cap=r_cap)
+
+    return jax.vmap(lambda R: jax.vmap(lambda n: curve(R, n))(strides))(rows)
+
+
+def streaming_window_extra(sd, comp):
+    """Extra cycles an op of `comp` compute cycles loses to bank
+    conflicts, from its slowdown curve `sd` (`streaming_slowdown_curve`).
+
+    Cycles past clip(floor(comp), 8, window) are masked out of the mean,
+    reproducing the op-sized window of the eager model exactly; the sum
+    is int32, so it is exact whatever the batch. `sd` may carry leading
+    batch axes matching `comp`'s shape.
+    """
+    n_cyc = STREAM_WINDOW_CYCLES
+    t = jnp.arange(n_cyc, dtype=jnp.int32)
     n_valid = jnp.clip(jnp.floor(jnp.minimum(1.0 * comp, n_cyc)), 8, n_cyc)
-    mean_sd = jnp.sum(jnp.where(t < n_valid, sd, 0)) / n_valid
-    return (mean_sd - 1.0) * comp
+    total = jnp.sum(jnp.where(t < n_valid[..., None], sd, 0), axis=-1)
+    return (total / n_valid - 1.0) * comp
+
+
+def streaming_layout_extra(cfg: LayoutConfig, R, comp, elem_stride,
+                           word_bytes: int = 2, *, r_cap: int = None,
+                           lead_stride: int = 1):
+    """Extra cycles a systolic streaming pattern loses to bank conflicts:
+    the slowdown curve of (R, elem_stride) averaged over the op's window.
+
+    The per-op form of the LayoutStage model; the batched sweep kernel
+    composes the same two functions over its distinct (R, stride) curves,
+    so both paths agree bit-for-bit. `R`, `comp` and `elem_stride` may be
+    traced scalars; `r_cap` defaults to R.
+    """
+    if r_cap is None:
+        r_cap = int(R)
+    sd = streaming_slowdown_curve(cfg, R, elem_stride, word_bytes,
+                                  r_cap=r_cap, lead_stride=lead_stride)
+    return streaming_window_extra(sd, comp)
 
 
 DRAM_LAYOUTS = ("row", "col", "tiled", "strided")

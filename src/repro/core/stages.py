@@ -30,7 +30,7 @@ from .accelerator import (AcceleratorConfig, LayoutConfig, MemoryConfig,
 from . import dataflow as dfm
 from .dram import simulate_dram, tile_prefetch_trace
 from .energy import DEFAULT_ERT, ERT, action_counts, action_counts_raw, energy_pj
-from .layout import streaming_layout_extra
+from .layout import streaming_layout_extra, streaming_window_extra
 from .multicore import best_multicore, best_multicore_cycles_model
 from .sparsity import (sparse_compute_cycles, sparse_compute_cycles_model,
                        storage_bytes_model, storage_report)
@@ -541,15 +541,14 @@ def traced_op_stats(dataflow: str, M, N, K, R, C, mem: MemoryConfig,
                     bw_bytes_per_cycle, *,
                     sparsity: Optional[Dict] = None,
                     multicore: Optional[Dict] = None,
-                    layout: Optional[Dict] = None) -> Dict[str, jnp.ndarray]:
+                    layout_sd=None) -> Dict[str, jnp.ndarray]:
     """Traced twin of the full fast-fidelity gemm pipeline (per op
-    instance; callers scale by count). `layout`: {'cfg': LayoutConfig
-    (static), 'r_cap': static bound on R}, or None to skip the layout
-    stage — layout on/off is a static kernel flavor (the Study plan
-    groups enabled and disabled cells separately, so disabled groups pay
-    nothing). See `traced_comp_traffic` for the sparsity/multicore
-    parameter shapes."""
-    import jax
+    instance; callers scale by count). `layout_sd`: each op's layout
+    slowdown curve (`layout.streaming_slowdown_curve`; op axes leading,
+    the window last), or None to skip the layout stage — layout on/off
+    is a static kernel flavor (the Study plan groups enabled and disabled
+    cells separately, so disabled groups pay nothing). See
+    `traced_comp_traffic` for the sparsity/multicore parameter shapes."""
     comp, sram, dram, shrink = traced_comp_traffic(
         dataflow, M, N, K, R, C, mem, sparsity=sparsity,
         multicore=multicore)
@@ -559,17 +558,8 @@ def traced_op_stats(dataflow: str, M, N, K, R, C, mem: MemoryConfig,
     stall = dfm.dram_stall_cycles_simple(dram_bytes, comp,
                                          bw_bytes_per_cycle)
     extra = jnp.zeros_like(comp)
-    if layout is not None:
-        lcfg, r_cap = layout["cfg"], layout["r_cap"]
-        stride = jnp.maximum(1.0, jnp.float32(1.0) * N)
-
-        def one_op(comp_, stride_):
-            return streaming_layout_extra(lcfg, R, comp_, stride_,
-                                          mem.word_bytes, r_cap=r_cap)
-
-        extra = (one_op(comp, stride) if jnp.ndim(comp) == 0
-                 else jax.vmap(one_op)(comp, jnp.broadcast_to(
-                     stride, jnp.shape(comp))))
+    if layout_sd is not None:
+        extra = streaming_window_extra(layout_sd, comp)
     return dict(compute_cycles=comp, stall_cycles=stall,
                 layout_extra_cycles=extra, dram_bytes=dram_bytes,
                 dram_elems=dram_elems, filter_shrink=shrink, **sram)

@@ -6,7 +6,8 @@ program in the `op_name` of its operations.  Neither records anything
 unless a profiler runs: wrap `Study.run()` in `jax.profiler.trace(dir)`.
 """
 
-# host spans; `sweep` carries program, designs, streams, blocks, block
+# host spans; `sweep` carries program, designs, streams, blocks, block,
+# layout_rows
 STUDY_RUN, STUDY_PLAN, STUDY_CACHE = "study.run", "study.plan", "study.cache"
 STUDY_FALLBACK, STUDY_FRAME = "study.fallback", "study.frame"
 SWEEP, SWEEP_COLUMNS = "sweep", "sweep.columns"

@@ -58,6 +58,40 @@ def test_a_traced_study_emits_every_host_span(tmp_path):
     # designs a and b share their stream: two streams per op
     assert sweep["streams"] == 2 * len(OPS)
     assert (sweep["blocks"], sweep["block"]) == (1, 4)
+    assert sweep["layout_rows"] == 0           # no layout stage
+
+
+def _layout_rows(cfgs, ops):
+    _, _, counts = _sweep_inputs(cfgs, ops, cfgs[0].dataflow,
+                                 cfgs[0].memory.word_bytes, DEFAULT_ERT,
+                                 None, None, None, "xla", 0)
+    return counts["layout_rows"]
+
+
+def _with_layout(cfgs, banks=32):
+    return [c.with_(layout=LayoutConfig(enabled=True, num_banks=banks))
+            for c in cfgs]
+
+
+@pytest.mark.parametrize("case", ["pairs", "no_layout", "screen"])
+def test_the_sweep_span_counts_layout_curves(case):
+    """`layout_rows`: the layout slowdown curves a program computes, one
+    per distinct (array rows, op stride) pair; 0 without layout."""
+    from repro.core.workloads import vit_base_linear
+    if case == "screen":
+        # the screen's shape: 48 ViT-base GEMMs, all at N = 197 tokens,
+        # on arrays 32/64/128
+        cfgs = _with_layout(preset_grid(array=[32, 64, 128, 64, 32],
+                                        sram_mb=[1.0, 4.0]))
+        assert _layout_rows(cfgs, vit_base_linear()) == 3
+        return
+    # strides 128 and 64 (op c repeats a's), arrays 16 and 32
+    ops = OPS + [Op("c", 32, 128, 16)]
+    cfgs = list(_designs().values())
+    if case == "no_layout":
+        assert _layout_rows(cfgs, ops) == 0
+    else:
+        assert _layout_rows(_with_layout(cfgs), ops) == 2 * 2
 
 
 def _lowered_hlo(cfgs, fidelity):
@@ -100,3 +134,14 @@ def test_a_fast_program_carries_stages_only():
 def test_program_names_follow_the_flavor(dataflow, kw, name):
     assert _batched_design_fn(dataflow, 1, DEFAULT_ERT, **kw).__name__ \
         == name
+
+
+def test_the_layout_curves_run_in_the_stages_scope():
+    """The layout stage's sort and scatter-add (the curve table) carry the
+    `stages` scope, so a device trace does not read them as unscoped."""
+    _, hlo = _lowered_hlo(_with_layout(list(_designs().values())), "fast")
+    ops = [ln for ln in hlo.splitlines()
+           if re.search(r"= s32\[[\d,]*\][^ ]* (sort|scatter)\(", ln)]
+    assert len(ops) == 2
+    for ln in ops:
+        assert _scoped(ln, spans.STAGES), ln

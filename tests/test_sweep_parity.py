@@ -153,3 +153,80 @@ def test_sparse_speedup_study_claims():
     res = studies.sparse_speedup(smoke=True).run()
     assert res.claims_ok(), res.check_claims()
     assert res.fraction_batched == 1.0
+
+
+# ---- the layout stage's per-program curve table ----------------------------
+
+def _screen_like_designs(n: int, banks: int = 32, seed: int = 0):
+    """`n` designs of one layout program of the search's fast screen:
+    arrays 32/64/128 in turn, one bank count, SRAM drawn from the Table V
+    axis (512 KiB up: 16 KiB a bank holds for 32 banks)."""
+    from repro.search.space import SearchPoint
+    from repro.search.studies import table_v_space
+    space = table_v_space()
+    ax = {a.name: a for a in space.axes}
+    srams = np.random.default_rng(seed).choice(
+        len(ax["sram_kb"].values), size=n, replace=False)
+    designs = {}
+    for i, s in enumerate(srams):
+        vals = dict(array=i % 3, sram_kb=int(s), dataflow=0, channels=0,
+                    bw=0, layout_banks=ax["layout_banks"].values.index(banks))
+        p = SearchPoint(tuple(vals[a.name] for a in space.axes))
+        assert space.is_valid(p)
+        designs[space.label(p)] = space.config(p)
+    return designs
+
+
+def test_screen_layout_program_equals_one_design_per_group():
+    """A screen-like fast Study (ViT-base's 48 GEMMs, arrays 32/64/128 in
+    one layout program: 3 curves) gives, column for column and exactly,
+    the frame of its designs run one design per program."""
+    from repro.core.workloads import vit_base_linear
+    designs = _screen_like_designs(60)
+    ops = vit_base_linear()
+    assert len(ops) == 48
+    study = Study().designs(designs).workloads({"vit": ops}).fidelity("fast")
+    assert len(study.plan().groups) == 1
+    res = study.run()
+    assert res.fraction_batched == 1.0 and not res.failed_cells
+    single = [Study().designs({k: c}).workloads({"vit": ops})
+              .fidelity("fast").run() for k, c in designs.items()]
+    for col in PARITY_COLUMNS + ("batched",):
+        one = np.concatenate([s[col] for s in single])
+        assert np.array_equal(res[col], one), col
+
+
+def test_layout_program_on_a_four_device_mesh_equals_unsharded():
+    """A layout group of 10 designs (not a multiple of 4, so the mesh pads
+    the `lay_row` design column like every other) gives the same frame on
+    a 4-device mesh as without one (8 forced host devices, in a
+    subprocess: the device count locks at the first jax init)."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.api import Study
+        from repro.core.workloads import vit_linear
+        from repro.launch.mesh import auto_mesh
+        from tests.test_sweep_parity import PARITY_COLUMNS, _screen_like_designs
+        mesh = auto_mesh((4,), ("data",), devices=jax.devices()[:4])
+        mk = lambda: (Study().designs(_screen_like_designs(10, banks=16))
+                      .workloads({"vit": vit_linear(768, 2, 3072)})
+                      .fidelity("fast"))
+        assert len(mk().plan().groups) == 1
+        plain, shard = mk().run(), mk().run(mesh=mesh)
+        assert plain.fraction_batched == shard.fraction_batched == 1.0
+        for col in PARITY_COLUMNS:
+            assert np.array_equal(plain[col], shard[col]), col
+        print("mesh layout frame matches")
+    """)
+    env = dict(os.environ,
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH=os.pathsep.join([os.path.join(root, "src"), root]))
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, timeout=560, cwd=root)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert "mesh layout frame matches" in out.stdout
